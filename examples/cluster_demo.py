@@ -74,9 +74,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax
-
-jax.config.update("jax_platform_name", "cpu")
-
 import numpy as np
 
 from repro.cluster import (ClusterSim, DeviceTopology, FleetScheduler,

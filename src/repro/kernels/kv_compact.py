@@ -42,13 +42,12 @@ def kv_compact(pool, src, dst, *, interpret: bool = True):
         out_specs=pl.BlockSpec(blk, lambda i, s, d: (d[i],) + (0,) *
                                (len(blk) - 1)),
     )
-    from repro.kernels.ops import tpu_compiler_params
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},   # pool (after 2 scalar args) -> out
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(src.astype(jnp.int32), dst.astype(jnp.int32), pool)

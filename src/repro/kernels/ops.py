@@ -11,17 +11,6 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(**kwargs):
-    """Compat shim over the ``pltpu.CompilerParams`` -> ``TPUCompilerParams``
-    rename: build whichever class this JAX ships.  Kernel modules import this
-    lazily (inside their builder functions) to avoid an import cycle."""
-    cls = getattr(pltpu, "TPUCompilerParams", None) or \
-        getattr(pltpu, "CompilerParams")
-    return cls(**kwargs)
-
 
 from repro.kernels import ref
 from repro.kernels.kv_compact import kv_compact as _kv_compact_kernel

@@ -96,12 +96,11 @@ def paged_attention(q, k_pool, v_pool, tables, positions, *,
             pltpu.VMEM((g, dh), f32),
         ],
     )
-    from repro.kernels.ops import tpu_compiler_params
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((p, hkv, g, dh), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tables.astype(jnp.int32), positions.astype(jnp.int32), q, k_pool,

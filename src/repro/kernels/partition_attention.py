@@ -100,12 +100,11 @@ def partition_attention(q, k_cache, v_cache, positions, *, window: int = 0,
             pltpu.VMEM((g, dh), f32),     # output accumulator
         ],
     )
-    from repro.kernels.ops import tpu_compiler_params
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((p, hkv, g, dh), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(positions.astype(jnp.int32), q, k_cache, v_cache)

@@ -13,6 +13,7 @@ import json
 
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import get_config, reduced
 from repro.core.arena import ArenaSpec
 from repro.models import model as M
@@ -55,6 +56,7 @@ def main() -> None:
     ap.add_argument("--keep-alive", type=float, default=3.0)
     ap.add_argument("--reduced", action="store_true")
     a = ap.parse_args()
+    enable_compile_cache()
     _, m = serve(a.arch, mode=a.mode, duration=a.duration, rate=a.rate,
                  n_partitions=a.partitions,
                  partition_tokens=a.partition_tokens,

@@ -51,7 +51,6 @@ def moe_block(cfg, p, x):
 
 
 def _moe_shard_map(cfg, p, x, ctx):
-    from jax.experimental.shard_map import shard_map
     from repro.sharding import spec_for, shard
     from jax.sharding import PartitionSpec as P
 
@@ -136,9 +135,9 @@ def _moe_shard_map(cfg, p, x, ctx):
         y = jax.lax.psum(y, "model")      # combine experts (EP) / F (TP)
         return y.reshape(bl, sl, dm)
 
-    fn = shard_map(local_fn, mesh=ctx.mesh,
-                   in_specs=(xs, P(None, None), gs, gs, ds_),
-                   out_specs=xs, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=ctx.mesh,
+                       in_specs=(xs, P(None, None), gs, gs, ds_),
+                       out_specs=xs, check_vma=False)
     return fn(x, p["router"], p["gate"], p["up"], p["down"])
 
 
