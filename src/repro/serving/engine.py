@@ -25,6 +25,14 @@ device operation (prefill, decode step, migration, zero-fill).  Arrivals are
 virtual-time stamped, so trace-driven benchmarks measure real relative costs
 (reclaim vs decode interference) without running 300 wall-clock seconds.
 
+Host spans: every phase of a tick is a ``jax.profiler.TraceAnnotation``
+named ``engine.<phase>``, so a profiled run shows it on the host plane of
+the same trace as the device ops, and is stamped with ``time.perf_counter``
+(see ``_tick``).  Each ``StepEvent`` carries the stamps of the work it
+measured; a decode event carries the host self time of its tick's phases.
+With the profiler off a span costs two clock reads and an inactive
+annotation.
+
 Modes (paper Fig. 8/9/10):
   hotmem  — partition arena; shrink is metadata + prefix slice.
   vanilla — same compute, but a physical paged twin of the KV leaves is
@@ -135,10 +143,68 @@ def assemble_pages(specs: list) -> np.ndarray:
 
 @dataclasses.dataclass
 class StepEvent:
-    t: float                 # virtual time at start
-    kind: str                # decode | prefill | plug | unplug
+    """One step of work in ``ServeEngine.events``.
+
+    ``t`` is the engine's virtual time at the event's end; ``kind`` is one
+    of ``decode``, ``prefill``, ``warm_start``, ``restore``, ``snapshot``,
+    ``plug``, ``unplug`` or ``stall``; ``wall_s`` is what the virtual clock
+    was charged for it.  ``start`` and ``end`` are ``time.perf_counter``
+    stamps of the host work measured for it (the clock of the broker's
+    order stamps); ``host_s`` is their difference.  ``modeled_s`` is the part of ``wall_s`` that no clock
+    measured: for a restore, the copy-on-write discount on pages this
+    replica already holds (negative) plus the modeled inter-host copy of a
+    migrated entry, so ``wall_s == host_s + modeled_s``; 0 for every other
+    kind.  A ``decode`` event's ``phases`` holds the host self time, in
+    seconds, of each phase of the tick that ran it (see
+    ``ServeEngine._tick``); ``launch + wait`` is its ``wall_s``."""
+    t: float
+    kind: str
     wall_s: float
     detail: dict[str, Any] = dataclasses.field(default_factory=dict)
+    start: float = 0.0
+    end: float = 0.0
+    modeled_s: float = 0.0
+    phases: Optional[dict[str, float]] = None
+
+    @property
+    def host_s(self) -> float:
+        return self.end - self.start
+
+
+def _covered_s(events, lo: float, hi: float) -> float:
+    """Seconds of ``[lo, hi]`` inside the measured extent of any event."""
+    total, edge = 0.0, lo
+    for a, b in sorted((ev.start, ev.end) for ev in events):
+        a, b = max(a, edge), min(b, hi)
+        if b > a:
+            total += b - a
+            edge = b
+    return total
+
+
+class _Phase:
+    """Host span ``engine.<name>`` on the profiler's clock that records its
+    self time as ``phases[key]``: its ``perf_counter`` wall less the
+    measured extent of the step events appended inside it.  Entering
+    returns the profiler annotation (``set_metadata`` adds arguments)."""
+    __slots__ = ("events", "phases", "key", "span", "n0", "t0")
+
+    def __init__(self, events: list, phases: dict, key: str,
+                 name: Optional[str] = None):
+        self.events, self.phases, self.key = events, phases, key
+        self.span = jax.profiler.TraceAnnotation(f"engine.{name or key}")
+
+    def __enter__(self):
+        self.span.__enter__()
+        self.n0 = len(self.events)
+        self.t0 = time.perf_counter()
+        return self.span
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self.phases[self.key] = t1 - self.t0 - _covered_s(
+            self.events[self.n0:], self.t0, t1)
+        return self.span.__exit__(*exc)
 
 
 class ServeEngine:
@@ -266,9 +332,12 @@ class ServeEngine:
     def _warm_decode(self, rows_n: int) -> None:
         if rows_n in self._decode_jit:
             return
-        self._decode_jit[rows_n] = jax.jit(
-            lambda p, t, po, c: M.decode_step(self.cfg, p, t, po, c),
-            donate_argnums=(3,))
+        cfg = self.cfg
+
+        def decode_step(params, toks, pos, caches):
+            return M.decode_step(cfg, params, toks, pos, caches)
+        # named, so the executable is ``jit_decode_step`` at every size
+        self._decode_jit[rows_n] = jax.jit(decode_step, donate_argnums=(3,))
         caches = M.init_caches(self.cfg, rows_n, self.spec.partition_tokens)
         toks = jnp.zeros((rows_n, 1), i32)
         pos = jnp.zeros((rows_n,), i32)
@@ -286,11 +355,13 @@ class ServeEngine:
         claimed at later ticks while our decode proceeds."""
         bpp = self.spec.blocks_per_partition
         want = native if self.mode == "vanilla" else native * bpp
+        t0 = time.perf_counter()
         g = self.broker.request_grant(self.replica_id, want)
         if g.stall_seconds:
             self.now += g.stall_seconds
             self.events.append(StepEvent(self.now, "stall", g.stall_seconds,
-                                         {"units": g.granted}))
+                                         {"units": g.granted}, start=t0,
+                                         end=time.perf_counter()))
         if not g.done:
             self._open_grants.append(g)
         got = g.granted
@@ -348,8 +419,11 @@ class ServeEngine:
         self.pending.append(req)
 
     # -------------------------------------------------------------- admit
-    def _try_admit(self) -> None:
+    def _try_admit(self) -> tuple[int, int]:
+        """Start every due pending request that finds a row; returns how
+        many pending requests were scanned and how many were admitted."""
         still = deque()
+        scanned = len(self.pending)
         while self.pending:
             req = self.pending.popleft()
             if req.submit_s > self.now:
@@ -363,7 +437,8 @@ class ServeEngine:
             warm = None if batch else self.warm.get(req.profile.name)
             if warm:
                 _, old_rid, row = warm.pop()
-                self._start_warm(req, old_rid, row)
+                with jax.profiler.TraceAnnotation("engine.warm", rid=req.rid):
+                    self._start_warm(req, old_rid, row)
                 continue
             got = self.arena.admit(req.rid)
             if got is None:
@@ -382,10 +457,15 @@ class ServeEngine:
                 and self.broker.snapshot_restorable(req.profile.name) \
                 else None
             if snap is not None:
-                self._start_restore(req, row, snap)
+                with jax.profiler.TraceAnnotation("engine.restore",
+                                                  rid=req.rid):
+                    self._start_restore(req, row, snap)
             else:
-                self._start_cold(req, row)
+                with jax.profiler.TraceAnnotation("engine.prefill",
+                                                  rid=req.rid):
+                    self._start_cold(req, row)
         self.pending = still
+        return scanned, scanned - len(still)
 
     def _alloc_row(self, req) -> Optional[int]:
         used = set(self._row_req)
@@ -417,20 +497,21 @@ class ServeEngine:
                          hash(prof.name) % 97 + 1, np.int32)
         n = prof.prompt_tokens
         if n not in self._prefill_jit:
-            def _pf(params, toks, row_caches):
+            def prefill(params, toks, row_caches):
                 return M.prefill(self.cfg, params, {"tokens": toks},
                                  row_caches)[1]
-            self._prefill_jit[n] = jax.jit(_pf, donate_argnums=(2,))
+            self._prefill_jit[n] = jax.jit(prefill, donate_argnums=(2,))
         t0 = time.perf_counter()
         row_caches = M.init_caches(self.cfg, 1, self.spec.partition_tokens)
         row_caches = self._prefill_jit[n](self.params, jnp.asarray(prompt),
                                           row_caches)
         self.caches = M.cache_write_row(self.caches, row_caches, row)
         jax.block_until_ready(jax.tree.leaves(self.caches)[0])
-        wall = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        wall = t1 - t0
         self.now += wall
         self.events.append(StepEvent(self.now, "prefill", wall,
-                                     {"rid": req.rid}))
+                                     {"rid": req.rid}, start=t0, end=t1))
         self._activate(req, row)
         self.cold_starts += 1
 
@@ -443,8 +524,10 @@ class ServeEngine:
         self.arena.manager.adopt(old_rid, req.rid)
         self._activate(req, row)
         self.warm_starts += 1
+        t = time.perf_counter()
         self.events.append(StepEvent(self.now, "warm_start", 0.0,
-                                     {"rid": req.rid, "row": row}))
+                                     {"rid": req.rid, "row": row},
+                                     start=t, end=t))
 
     def _start_restore(self, req: Request, row: int, snap) -> None:
         """Snapshot restore: the function's prefix KV was persisted to the
@@ -512,7 +595,8 @@ class ServeEngine:
                 layout=layout, impl=self.snapshot_impl)
             kv_snapshot.note_launch("restore")
         jax.block_until_ready(jax.tree.leaves(self.caches)[0])
-        wall = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        wall = t1 - t0
         detail = {"rid": req.rid, "key": snap.key, "bytes": snap.nbytes,
                   "row": row, "source": "remote" if copy_s else "local"}
         if specs is not None:
@@ -520,7 +604,8 @@ class ServeEngine:
             shared = sum(1 for d, _u, _b, _p in specs if d in self._mapped)
             # CoW: only the new pages pay the host->device copy; shared
             # pages are a mapping (the measured wall is the full row
-            # write, so scale it by the new-page fraction)
+            # write, so the charge scales it by the new-page fraction:
+            # a modeled discount, kept apart in ``modeled_s``)
             wall *= (total - shared) / total if total else 1.0
             self._mapped.update(d for d, _u, _b, _p in specs)
             self._index_device_pages(dev_blob, specs)
@@ -532,54 +617,74 @@ class ServeEngine:
             detail["origin"] = snap.origin_host
             detail["copy_s"] = copy_s
             self.remote_restore_starts += 1
-        self.events.append(StepEvent(self.now, "restore", wall, detail))
+        self.events.append(StepEvent(self.now, "restore", wall, detail,
+                                     start=t0, end=t1,
+                                     modeled_s=wall - (t1 - t0)))
         self._activate(req, row)
         self.restore_starts += 1
 
     # -------------------------------------------------------------- decode
-    def _decode(self) -> None:
+    def _decode(self, phases: Optional[dict] = None) -> None:
+        """One decode step over every arena row, then the per-row
+        bookkeeping.  Host spans ``engine.decode`` (tiled by ``.inputs``,
+        ``.launch`` up to the return of the jitted call, and ``.wait``
+        for its result) and ``engine.decode.post``; their self times go
+        into ``phases`` and the step's event."""
+        phases = {} if phases is None else phases
         rows = self._rows()
-        toks = np.zeros((rows, 1), np.int32)
-        pos = np.zeros((rows,), np.int32)
-        for row, req in self._row_req.items():
-            assert row < rows, \
-                f"active request {req.rid} bound to row {row} but the " \
-                f"arena holds only {rows} rows — a shrink dropped a live " \
-                f"row (free-suffix invariant violated)"
-            pos[row] = req.position
         if rows not in self._decode_jit:
             self._warm_decode(rows)
-        t0 = time.perf_counter()
-        logits, self.caches = self._decode_jit[rows](
-            self.params, jnp.asarray(toks), jnp.asarray(pos), self.caches)
-        jax.block_until_ready(logits)
-        wall = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("engine.decode"):
+            t_in = time.perf_counter()
+            with jax.profiler.TraceAnnotation("engine.decode.inputs"):
+                toks = np.zeros((rows, 1), np.int32)
+                pos = np.zeros((rows,), np.int32)
+                for row, req in self._row_req.items():
+                    assert row < rows, \
+                        f"active request {req.rid} bound to row {row} but " \
+                        f"the arena holds only {rows} rows — a shrink " \
+                        f"dropped a live row (free-suffix invariant " \
+                        f"violated)"
+                    pos[row] = req.position
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("engine.decode.launch"):
+                logits, self.caches = self._decode_jit[rows](
+                    self.params, jnp.asarray(toks), jnp.asarray(pos),
+                    self.caches)
+            t1 = time.perf_counter()
+            with jax.profiler.TraceAnnotation("engine.decode.wait"):
+                jax.block_until_ready(logits)
+            t2 = time.perf_counter()
+        phases.update(inputs=t0 - t_in, launch=t1 - t0, wait=t2 - t1)
+        wall = t2 - t0
         self.now += wall
         self.events.append(StepEvent(self.now, "decode", wall,
-                                     {"batch": len(self._row_req)}))
-        finished = []
-        for row, req in list(self._row_req.items()):
-            req.position += 1
-            if req.first_token_s is None:
-                req.first_token_s = self.now
-            if not self.arena.on_tokens(req.rid, 1):
-                req.state = State.KILLED
-                finished.append((row, req))
-                continue
-            if req.position >= req.target_tokens:
-                req.state = State.DONE
-                finished.append((row, req))
-        for row, req in finished:
-            req.done_s = self.now
-            self.done.append(req)
-            del self.active[req.rid]
-            del self._row_req[row]
-            if req.state is State.DONE:
-                # to warm pool: the partition STAYS BOUND (idle container)
-                # until keep-alive expiry recycles it
-                self.warm.setdefault(req.profile.name, []).append(
-                    (self.now, req.rid, row))
-            # KILLED was already force-released by the manager
+                                     {"batch": len(self._row_req)},
+                                     start=t0, end=t2, phases=phases))
+        with _Phase(self.events, phases, "post", "decode.post"):
+            finished = []
+            for row, req in list(self._row_req.items()):
+                req.position += 1
+                if req.first_token_s is None:
+                    req.first_token_s = self.now
+                if not self.arena.on_tokens(req.rid, 1):
+                    req.state = State.KILLED
+                    finished.append((row, req))
+                    continue
+                if req.position >= req.target_tokens:
+                    req.state = State.DONE
+                    finished.append((row, req))
+            for row, req in finished:
+                req.done_s = self.now
+                self.done.append(req)
+                del self.active[req.rid]
+                del self._row_req[row]
+                if req.state is State.DONE:
+                    # to warm pool: the partition STAYS BOUND (idle
+                    # container) until keep-alive expiry recycles it
+                    self.warm.setdefault(req.profile.name, []).append(
+                        (self.now, req.rid, row))
+                # KILLED was already force-released by the manager
 
     # ------------------------------------------------------------- elastic
     def _snapshot_layout(self):
@@ -653,7 +758,8 @@ class ServeEngine:
         dev_blob = M.cache_read_rows(self.caches, jnp.asarray([row], i32),
                                      layout=layout, impl=self.snapshot_impl)
         host = np.asarray(jax.device_get(dev_blob))
-        wall = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        wall = t1 - t0
         kv_snapshot.note_launch("capture")
         kv_snapshot.note_d2h(host.nbytes)
         blob_u8 = host.view(np.uint8).reshape(-1)    # zero-copy byte image
@@ -677,7 +783,8 @@ class ServeEngine:
             self.now += wall
             self.events.append(StepEvent(self.now, "snapshot", wall,
                                          {"key": prof_name, "rid": rid,
-                                          "bytes": nbytes, "row": row}))
+                                          "bytes": nbytes, "row": row},
+                                         start=t0, end=t1))
         return ok
 
     def _paginate(self, blob_u8: np.ndarray, units: int) -> list:
@@ -725,7 +832,9 @@ class ServeEngine:
                 # requester is waiting on, and the next pressured grant
                 # would squeeze the snapshot right back
                 t, rid, row = max(expired)       # newest expiring entry
-                self._offer_snapshot(prof, rid, row)
+                with jax.profiler.TraceAnnotation("engine.snapshot",
+                                                  rid=rid):
+                    self._offer_snapshot(prof, rid, row)
             for (_, rid, _row) in expired:
                 self.arena.finish(rid)
             self.warm[prof] = fresh
@@ -773,23 +882,29 @@ class ServeEngine:
         event log — the one plug protocol both growth paths share (the
         bit-identical-trace regression depends on it staying identical).
         Returns native units actually added."""
-        before = self.arena.units()
-        # absorb path: the claimed fill is a whole stripe (claim_grant only
-        # releases coherent units); hotmem's native unit is a partition,
-        # which stripes wholly, so only vanilla's block granules need the
-        # stripe check — and vanilla is asserted off for sharded hosts.
-        wall = self.arena.plug(native) if via_gate \
-            else self.arena.absorb(
-                native, shards=self._n_dev if self.mode == "vanilla" else 1)
-        added = self.arena.units() - before
-        if added:
-            t0 = time.perf_counter()
-            self._sync_rows(self._units())
-            jax.block_until_ready(jax.tree.leaves(self.caches)[0])
-            wall += time.perf_counter() - t0
-            self.now += wall
-            self.events.append(StepEvent(self.now, "plug", wall,
-                                         {"units": added, **(detail or {})}))
+        with jax.profiler.TraceAnnotation("engine.plug"):
+            start = time.perf_counter()
+            before = self.arena.units()
+            # absorb path: the claimed fill is a whole stripe (claim_grant
+            # only releases coherent units); hotmem's native unit is a
+            # partition, which stripes wholly, so only vanilla's block
+            # granules need the stripe check — and vanilla is asserted off
+            # for sharded hosts.
+            wall = self.arena.plug(native) if via_gate \
+                else self.arena.absorb(
+                    native,
+                    shards=self._n_dev if self.mode == "vanilla" else 1)
+            added = self.arena.units() - before
+            if added:
+                t0 = time.perf_counter()
+                self._sync_rows(self._units())
+                jax.block_until_ready(jax.tree.leaves(self.caches)[0])
+                t1 = time.perf_counter()
+                wall += t1 - t0
+                self.now += wall
+                self.events.append(StepEvent(
+                    self.now, "plug", wall,
+                    {"units": added, **(detail or {})}, start=start, end=t1))
         return added
 
     def _unplug_now(self, units: int, *, stolen: bool = False
@@ -797,18 +912,21 @@ class ServeEngine:
         """Unplug + row sync + virtual-clock charge + event log — shared by
         self-initiated shrink and broker-initiated steals (which do their
         own host accounting, hence ``notify_host=False``)."""
-        ev = self.arena.unplug(units, notify_host=not stolen)
-        t0 = time.perf_counter()
-        self._sync_rows(self._units())
-        jax.block_until_ready(jax.tree.leaves(self.caches)[0])
-        ev.wall_seconds += time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation("engine.unplug"):
+            start = time.perf_counter()
+            ev = self.arena.unplug(units, notify_host=not stolen)
+            t0 = time.perf_counter()
+            self._sync_rows(self._units())
+            jax.block_until_ready(jax.tree.leaves(self.caches)[0])
+            t1 = time.perf_counter()
+            ev.wall_seconds += t1 - t0
         self.now += ev.wall_seconds
         detail = {"reclaimed_bytes": ev.reclaimed_bytes,
                   "migrated_bytes": ev.migrated_bytes}
         if stolen:
             detail["stolen"] = True
         self.events.append(StepEvent(self.now, "unplug", ev.wall_seconds,
-                                     detail))
+                                     detail, start=start, end=t1))
         return ev
 
     def _sync_rows(self, parts: int) -> None:
@@ -979,23 +1097,37 @@ class ServeEngine:
         """One scheduler iteration: submit due arrivals, admit, decode (or
         let time pass), recycle idle containers, resize.  ``run`` loops
         this for a standalone replica; ``ClusterSim`` interleaves ticks
-        across replicas in virtual-time order."""
+        across replicas in virtual-time order.
+
+        Each phase is a host span on the profiler's clock (``engine.grants``,
+        ``.orders``, ``.admit``, ``.decode``, ``.decode.post``,
+        ``.recycle``, ``.resize``; the start paths, snapshots and resizes
+        inside them are spans too).  The self time of each phase lands in
+        the ``phases`` of the tick's decode event, if it ran one: ``grants``,
+        ``orders``, ``admit`` and ``inputs`` before the step, ``launch``
+        and ``wait`` of the step, ``post``, ``recycle`` and ``resize``
+        after it."""
+        phases: dict[str, float] = {}
         while todo and todo[0].submit_s <= self.now:
             self.submit(todo.popleft())
         # async host pipeline first: claim grant fills (rows grow before
         # admission) and drain one chunk of any open reclaim order — both
         # at this tick boundary, never inside another replica's request.
         # A fully idle engine abandons pending grants: its demand is gone.
-        self._claim_grants(abandon=not todo and not self.active
-                           and not self.pending
-                           and not any(self.warm.values()))
-        self._service_reclaim_orders()
+        with _Phase(self.events, phases, "grants"):
+            self._claim_grants(abandon=not todo and not self.active
+                               and not self.pending
+                               and not any(self.warm.values()))
+        with _Phase(self.events, phases, "orders"):
+            self._service_reclaim_orders()
         if not self.active and not self.pending and todo:
             self.now = max(self.now, todo[0].submit_s)
             return
-        self._try_admit()
+        with _Phase(self.events, phases, "admit") as span:
+            scanned, admitted = self._try_admit()
+            span.set_metadata(scanned=scanned, admitted=admitted)
         if self._row_req:
-            self._decode()
+            self._decode(phases)
         elif self.pending:
             # stuck in waitqueue: let time pass so warm rows expire /
             # the next resize can plug (regardless of future arrivals)
@@ -1004,8 +1136,10 @@ class ServeEngine:
             # drain: idle containers age out, triggering final unplugs
             # (the paper's post-burst scale-down, Fig. 8)
             self.now += self.keep_alive / 8
-        self._recycle_idle()
-        self._resize()
+        with _Phase(self.events, phases, "recycle"):
+            self._recycle_idle()
+        with _Phase(self.events, phases, "resize"):
+            self._resize()
 
     def run(self, requests: list[Request], max_virtual_s: float = 1e9):
         todo = deque(sorted(requests, key=lambda r: r.submit_s))

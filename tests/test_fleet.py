@@ -573,6 +573,9 @@ def test_fleet_remote_restore_end_to_end(setup):
     assert ev.detail["origin"] == "h1"
     assert ev.detail["copy_s"] == pytest.approx(rec.copy_seconds)
     assert ev.wall_s >= rec.copy_seconds       # the copy was charged
+    # measured and modeled kept apart: the modeled part is the copy
+    assert ev.modeled_s == pytest.approx(rec.copy_seconds)
+    assert ev.wall_s == pytest.approx(ev.host_s + ev.modeled_s)
     # paid once: a second restore of the now-local entry is local again
     A.now += A.keep_alive + 1.0
     A._recycle_idle()
