@@ -132,10 +132,16 @@ def _decode_attention(q, cache_k, cache_v, pos, window, scale, cap):
     return _weighted(cache_v, w)                             # (B,1,K,G,D)
 
 
-def attention(cfg, p, x, *, positions, mode: str, cache=None,
+def attention(cfg, p, x, *, positions, mode: str, cache=None, layer=0,
               window: int = 0):
     """Returns (y, new_cache).  ``positions``: (B,S) [or (3,B,S) M-RoPE] for
-    train/prefill; (B,) [or (3,B)] global positions for decode."""
+    train/prefill; (B,) [or (3,B)] global positions for decode.
+
+    Prefill takes and returns one layer's cache (B,T,K,D).  Decode takes
+    the stack of every layer's cache at this block position (L,B,T,K,D)
+    and ``layer``, the index of this one; it writes the new token into the
+    stack in place and returns the stack (see ``stack_of_one`` for a block
+    outside a stack)."""
     hq, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     g = hq // hkv
     scale = cfg.query_scale or dh ** -0.5
@@ -188,21 +194,33 @@ def attention(cfg, p, x, *, positions, mode: str, cache=None,
         q = shard(q, "batch", None, None, None)
         k = shard(k, "batch", None, None, None)
         v = shard(v, "batch", None, None, None)
-        t = cache["k"].shape[1]
+        # One scatter of B tokens into the stack, then a read-only view of
+        # this layer for the attention math: the stack stays in place, and
+        # no layer-sized slab is copied out of it or back.
+        t = cache["k"].shape[2]
         idx = tok_pos % t                                # (B,)
         rows = jnp.arange(b)
-        ck = cache["k"].at[rows, idx].set(k[:, 0])
-        cv = cache["v"].at[rows, idx].set(v[:, 0])
-        ck = shard(ck, "batch", "kv_seq", "kv_heads", None)
-        cv = shard(cv, "batch", "kv_seq", "kv_heads", None)
+        ck = cache["k"].at[layer, rows, idx].set(k[:, 0])
+        cv = cache["v"].at[layer, rows, idx].set(v[:, 0])
+        ck = shard(ck, None, "batch", "kv_seq", "kv_heads", None)
+        cv = shard(cv, None, "batch", "kv_seq", "kv_heads", None)
         qg = q.reshape(b, s, hkv, g, dh)
-        out = _decode_attention(qg, ck, cv, tok_pos, window, scale, cap)
+        out = _decode_attention(
+            qg, jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False),
+            jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False),
+            tok_pos, window, scale, cap)
         out = shard(out.reshape(b, s, hq * dh), "batch", "seq", None)
         return dense(p["o"], out), {"k": ck, "v": cv}
 
     out = out.reshape(b, s, hq * dh)
     out = shard(out, "batch", "seq", "heads")
     return dense(p["o"], out), new_cache
+
+
+def stack_of_one(cache):
+    """One layer's cache (B,T,K,D) as a layer stack of one, the form
+    ``attention`` decodes into (index 0; ``l[0]`` takes it back out)."""
+    return jax.tree.map(lambda l: l[None], cache)
 
 
 def make_attn_cache_spec(cfg, batch: int, cache_len: int, window: int = 0):

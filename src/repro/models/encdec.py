@@ -121,11 +121,11 @@ def run_decoder(cfg, params, x, *, mode: str, caches=None, positions=None,
 
     from repro.tracemode import scan_unroll
 
-    def body(h, p, c):
+    def body(h, p, c, layer=0):
         hh, self_c = attn_mod.attention(
             cfg, p["self"], rmsnorm(p["ln1"], h, cfg.norm_eps),
             positions=positions, mode=mode,
-            cache=c["self"] if has_cache else None)
+            cache=c["self"] if has_cache else None, layer=layer)
         h = h + hh
         hh, cross_c = _cross_attention(
             cfg, p["cross"], rmsnorm(p["ln2"], h, cfg.norm_eps),
@@ -147,15 +147,24 @@ def run_decoder(cfg, params, x, *, mode: str, caches=None, positions=None,
                             unroll=scan_unroll())
         new_caches = None
     else:
-        # caches ride in the carry (in-place while pattern; see
-        # transformer.run_decoder)
+        # caches ride in the carry (see transformer.run_decoder).  In
+        # decode, self-attention writes its token into the whole stack in
+        # place and the cross-attention cache is only read, so no layer's
+        # slab is written back; prefill writes each layer's whole.
+        def layer_of(tree, bi):
+            return jax.tree.map(lambda l: jax.lax.dynamic_index_in_dim(
+                l, bi, 0, keepdims=False), tree)
+
         def block(carry, xs):
             h, bcaches = carry
             p, bi = xs
-            c = jax.tree.map(
-                lambda l: jax.lax.dynamic_index_in_dim(
-                    l, bi, 0, keepdims=False), bcaches)
-            h, nc = body(h, p, c)
+            if mode == "decode":
+                h, nc = body(h, p, {"self": bcaches["self"],
+                                    "cross": layer_of(bcaches["cross"], bi)},
+                             layer=bi)
+                return (h, {"self": nc["self"],
+                            "cross": bcaches["cross"]}), None
+            h, nc = body(h, p, layer_of(bcaches, bi))
             bcaches = jax.tree.map(
                 lambda l, n: jax.lax.dynamic_update_index_in_dim(
                     l, n, bi, 0), bcaches, nc)

@@ -98,7 +98,11 @@ def embed_specs(cfg):
 # ---------------------------------------------------------------------------
 
 
-def block_forward(cfg, kind: str, p, x, *, mode: str, cache, positions):
+def block_forward(cfg, kind: str, p, x, *, mode: str, cache, positions,
+                  layer=0):
+    """One block.  An attention block in decode mode takes and returns the
+    stack of its position's layer caches, ``layer`` indexing its own
+    (``attention``); every other block takes and returns its own cache."""
     if kind == "ssm":
         h, nc = ssm_mod.ssm_block(cfg, p["mixer"],
                                   rmsnorm(p["ln"], x, cfg.norm_eps),
@@ -115,7 +119,7 @@ def block_forward(cfg, kind: str, p, x, *, mode: str, cache, positions):
     h, nc = attn_mod.attention(cfg, p["attn"],
                                rmsnorm(p["ln1"], x, cfg.norm_eps),
                                positions=positions, mode=mode, cache=cache,
-                               window=_window_for(cfg, kind))
+                               layer=layer, window=_window_for(cfg, kind))
     if cfg.post_norms:
         h = rmsnorm(p["pn1"], h, cfg.norm_eps)
     x = x + h
@@ -127,15 +131,28 @@ def block_forward(cfg, kind: str, p, x, *, mode: str, cache, positions):
     return x + h2, nc
 
 
+def _decodes_into_stack(mode: str, kind: str) -> bool:
+    return mode == "decode" and kind in ATTN_KINDS
+
+
 def run_decoder(cfg, params, x, *, mode: str, caches=None, positions=None,
                 remat: bool = False):
     """x (B,S,D) -> (y (B,S,D), new_caches).
 
-    With caches, the stacked cache tree rides in the scan CARRY and each
-    group updates its slice via dynamic_update — the classic XLA in-place
-    while-loop pattern.  (Passing caches as scan xs/ys materializes full
-    stacked input AND output buffers as temps: several extra cache-sized
-    copies per step, blowing the 16 GiB budget for 70B-class decode.)"""
+    With caches, the stacked cache tree rides in the scan CARRY, so XLA
+    updates it in place across the layer loop.  (Passing caches as scan
+    xs/ys materializes full stacked input AND output buffers as temps:
+    several extra cache-sized copies per step, blowing the 16 GiB budget
+    for 70B-class decode.)
+
+    In decode, an attention block gets its position's whole KV stack and
+    the layer index: it scatters the new token into the stack at
+    ``[layer, row, pos % T]`` and reads its layer from the stack read-only
+    for the attention math.  Slicing the layer's (B,T,K,D) slab out and
+    writing it back instead copies the slab out of the carry and in again,
+    K and V, every layer and step, to store B tokens.  SSM and RG-LRU
+    state is rewritten whole every step, and prefill writes whole slabs,
+    so those keep the slice / write-back."""
     pattern = cfg.block_pattern
     has_cache = caches is not None
     from repro.tracemode import scan_unroll
@@ -162,19 +179,22 @@ def run_decoder(cfg, params, x, *, mode: str, caches=None, positions=None,
             gp, gi = xs
             new_gc = []
             for i, kind in enumerate(pattern):
-                c = jax.tree.map(
-                    lambda l: jax.lax.dynamic_index_in_dim(
-                        l, gi, 0, keepdims=False), gcaches[i])
-                h, nc = block_forward(cfg, kind, gp[i], h, mode=mode,
-                                      cache=c, positions=positions)
-                new_gc.append(nc)
-            gcaches = tuple(
-                jax.tree.map(
-                    lambda l, n: jax.lax.dynamic_update_index_in_dim(
-                        l, n, gi, 0), gcaches[i], new_gc[i])
-                for i in range(len(pattern)))
+                if _decodes_into_stack(mode, kind):
+                    h, gc = block_forward(cfg, kind, gp[i], h, mode=mode,
+                                          cache=gcaches[i],
+                                          positions=positions, layer=gi)
+                else:
+                    c = jax.tree.map(
+                        lambda l: jax.lax.dynamic_index_in_dim(
+                            l, gi, 0, keepdims=False), gcaches[i])
+                    h, nc = block_forward(cfg, kind, gp[i], h, mode=mode,
+                                          cache=c, positions=positions)
+                    gc = jax.tree.map(
+                        lambda l, n: jax.lax.dynamic_update_index_in_dim(
+                            l, n, gi, 0), gcaches[i], nc)
+                new_gc.append(gc)
             h = shard(h, "batch", "seq", "embed")
-            return (h, gcaches), None
+            return (h, tuple(new_gc)), None
 
         gi = jnp.arange(cfg.n_groups, dtype=jnp.int32)
         (x, group_caches), _ = jax.lax.scan(
@@ -184,9 +204,12 @@ def run_decoder(cfg, params, x, *, mode: str, caches=None, positions=None,
     tail_caches = []
     for i, kind in enumerate(cfg.tail_pattern):
         c = caches["tail"][i] if has_cache else None
+        stacked = _decodes_into_stack(mode, kind)
         x, nc = block_forward(cfg, kind, params["tail"][i], x, mode=mode,
-                              cache=c, positions=positions)
-        tail_caches.append(nc)
+                              cache=attn_mod.stack_of_one(c) if stacked
+                              else c, positions=positions)
+        tail_caches.append(jax.tree.map(lambda l: l[0], nc) if stacked
+                           else nc)
 
     new_caches = ({"groups": group_caches, "tail": tuple(tail_caches)}
                   if has_cache else None)

@@ -2,12 +2,19 @@
 forward for every architecture (validates cache semantics end to end —
 ring buffers, SSD state handoff, RG-LRU state, cross-attention caches,
 per-row positions)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs.base import get_config, list_archs, reduced
+from repro.models import attention as A
 from repro.models import model as M
+from repro.models import transformer as T
+from repro.models.layers import apply_rope, dense, mlp, rmsnorm
+from repro.models.moe import moe_block
+from repro.tracemode import scan_unroll
 
 B, S = 2, 12
 
@@ -85,3 +92,101 @@ def test_continuous_batching_rows_independent(rng):
     err = float(jnp.max(jnp.abs(
         cur[1].astype(jnp.float32) - fresh[1].astype(jnp.float32))))
     assert err < 0.35, err
+
+
+def _slab_attention_block(cfg, kind, p, x, pos, cache):
+    """An attention block's decode on one layer's (B,T,K,D) slab: the new
+    token written into the slab, attention over the slab."""
+    hq, hkv, dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    b = x.shape[0]
+    a = p["attn"]
+    h = rmsnorm(p["ln1"], x, cfg.norm_eps)
+    q = A._split_heads(dense(a["q"], h), hq, dh)
+    k = A._split_heads(dense(a["k"], h), hkv, dh)
+    v = A._split_heads(dense(a["v"], h), hkv, dh)
+    q = apply_rope(q, pos[..., None], cfg.rope_theta, cfg.mrope_sections)
+    k = apply_rope(k, pos[..., None], cfg.rope_theta, cfg.mrope_sections)
+    tok_pos = pos[0] if cfg.mrope_sections else pos
+    idx = tok_pos % cache["k"].shape[1]
+    ck = cache["k"].at[jnp.arange(b), idx].set(k[:, 0])
+    cv = cache["v"].at[jnp.arange(b), idx].set(v[:, 0])
+    out = A._decode_attention(
+        q.reshape(b, 1, hkv, hq // hkv, dh), ck, cv, tok_pos,
+        T._window_for(cfg, kind), cfg.query_scale or dh ** -0.5,
+        cfg.attn_logit_softcap)
+    h = dense(a["o"], out.reshape(b, 1, hq * dh))
+    if cfg.post_norms:
+        h = rmsnorm(p["pn1"], h, cfg.norm_eps)
+    x = x + h
+    h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
+    h2 = moe_block(cfg, p["ffn"], h2) if cfg.num_experts \
+        else mlp(cfg, p["ffn"], h2)
+    if cfg.post_norms:
+        h2 = rmsnorm(p["pn2"], h2, cfg.norm_eps)
+    return x + h2, {"k": ck, "v": cv}
+
+
+def _slab_carry_decode_step(cfg, params, tokens, positions, caches):
+    """``decode_step`` with the layer loop that slices every layer's cache
+    out of the scan carry, updates it and writes it back whole: the
+    reference the in-place stacked write must match bit for bit."""
+    dec = params["decoder"]
+    pos = M._decode_positions(cfg, positions)
+
+    def block(kind, p, h, c):
+        if kind in T.ATTN_KINDS:
+            return _slab_attention_block(cfg, kind, p, h, pos, c)
+        return T.block_forward(cfg, kind, p, h, mode="decode", cache=c,
+                               positions=pos)
+
+    def group_fn(carry, xs):
+        h, gcaches = carry
+        gp, gi = xs
+        new = []
+        for i, kind in enumerate(cfg.block_pattern):
+            c = jax.tree.map(lambda l: jax.lax.dynamic_index_in_dim(
+                l, gi, 0, keepdims=False), gcaches[i])
+            h, nc = block(kind, gp[i], h, c)
+            new.append(jax.tree.map(
+                lambda l, n: jax.lax.dynamic_update_index_in_dim(
+                    l, n, gi, 0), gcaches[i], nc))
+        return (h, tuple(new)), None
+
+    x = T.embed_tokens(cfg, params["embed"], tokens)
+    gi = jnp.arange(cfg.n_groups, dtype=jnp.int32)
+    (x, groups), _ = jax.lax.scan(group_fn, (x, caches["groups"]),
+                                  (dec["groups"], gi), unroll=scan_unroll())
+    tail = []
+    for i, kind in enumerate(cfg.tail_pattern):
+        x, nc = block(kind, dec["tail"][i], x, caches["tail"][i])
+        tail.append(nc)
+    x = rmsnorm(dec["final_norm"], x, cfg.norm_eps)
+    logits = T.lm_logits(cfg, params["embed"], x)
+    return logits[:, 0], {"groups": groups, "tail": tuple(tail)}
+
+
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "gemma2-9b",
+                                  "recurrentgemma-2b", "mamba2-780m"])
+def test_in_place_kv_write_matches_slab_carry(arch, rng):
+    """20 decode steps with per-row positions that wrap a 16-slot ring:
+    logits and every cache leaf equal the slab-carry loop's exactly."""
+    cfg = reduced(get_config(arch))
+    params = M.init_params(cfg, rng)
+    rows, ring, steps = 3, 16, 20
+    leaves, treedef = jax.tree.flatten(M.init_caches(cfg, rows, ring))
+    keys = jax.random.split(jax.random.fold_in(rng, 1), len(leaves))
+    caches = jax.tree.unflatten(treedef, [
+        jax.random.normal(k, l.shape).astype(l.dtype)
+        for k, l in zip(keys, leaves)])
+    toks = jax.random.randint(jax.random.fold_in(rng, 2), (steps, rows, 1),
+                              0, cfg.vocab_size)
+    start = jnp.asarray([0, 7, 13], jnp.int32)   # up to 32: wraps twice
+    new_step = jax.jit(functools.partial(M.decode_step, cfg))
+    ref_step = jax.jit(functools.partial(_slab_carry_decode_step, cfg))
+    got, want = caches, caches
+    for i in range(steps):
+        lg, got = new_step(params, toks[i], start + i, got)
+        lw, want = ref_step(params, toks[i], start + i, want)
+        assert bool(jnp.array_equal(lg, lw)), (arch, i)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bool(jnp.array_equal(a, b)), arch
